@@ -1,9 +1,12 @@
 """Public kernel entry points.
 
-Each op dispatches: Pallas kernel on TPU, Pallas-interpret when
+Each op dispatches: Pallas-interpret when
 ``REPRO_FORCE_PALLAS_INTERPRET=1`` (kernel-path testing on CPU), else the
-pure-jnp reference.  The reference IS the semantics; tests assert the
-kernel path matches it over shape/dtype sweeps.
+compiled Pallas kernel on TPU, else the pure-jnp reference.  Interpret
+mode comes from that variable alone, never from the platform, so a TPU
+run never interprets a kernel by accident.  The reference IS the
+semantics; tests assert the kernel path matches it over shape/dtype
+sweeps.
 """
 from __future__ import annotations
 
@@ -57,7 +60,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if _use_kernel():
         return _fa.flash_attention(q, k, v, causal=causal, window=window,
                                    block_q=block_q, block_k=block_k,
-                                   interpret=_platform() != "tpu")
+                                   interpret=_force_interpret())
     return _fa_ref_jit(q, k, v, causal, window)
 
 
@@ -74,7 +77,7 @@ def paged_attention(q, k_pool, v_pool, kpos_pool, block_table, pos, *,
     if _use_kernel():
         return _pa.paged_attention(q, k_pool, v_pool, kpos_pool,
                                    block_table, pos, window=window,
-                                   interpret=_platform() != "tpu")
+                                   interpret=_force_interpret())
     return _pa_ref_jit(q, k_pool, v_pool, kpos_pool, block_table, pos,
                        window)
 
@@ -105,13 +108,12 @@ def rwkv6_scan(r, k, v, w, u, s0=None, *, chunk: int = 32):
     """Chunked WKV6; returns (out, final_state)."""
     if _use_kernel():
         return _rwkv.rwkv6_scan(r, k, v, w, u, s0, chunk=chunk,
-                                interpret=_platform() != "tpu")
+                                interpret=_force_interpret())
     return _ref.rwkv6_scan_ref(r, k, v, w, u, s0)
 
 
-def conv2d(x, w, *, block_b: int = 128):
+def conv2d(x, w):
     """Valid NHWC conv, stride 1."""
     if _use_kernel():
-        return _conv.conv2d(x, w, block_b=block_b,
-                            interpret=_platform() != "tpu")
+        return _conv.conv2d(x, w, interpret=_force_interpret())
     return _ref.conv2d_ref(x, w)

@@ -53,7 +53,7 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)            # (H, hd)
     k = k_ref[0].astype(jnp.float32)            # (bs, KV, hd)
     v = v_ref[0].astype(jnp.float32)
-    kpos = kpos_ref[0]                          # (bs,)
+    kpos = kpos_ref[0, 0]                       # (bs,)
     h = q.shape[0]
     g = h // kv
 
@@ -114,7 +114,10 @@ def paged_attention(q, k_pool, v_pool, kpos_pool, block_table, pos, *,
                          lambda bi, ji, bt, ps: (bt[bi, ji], 0, 0, 0)),
             pl.BlockSpec((1, bs, kv, hd),
                          lambda bi, ji, bt, ps: (bt[bi, ji], 0, 0, 0)),
-            pl.BlockSpec((1, bs), lambda bi, ji, bt, ps: (bt[bi, ji], 0)),
+            # unit middle axis: a (1, bs) block over (NB, bs) breaks the
+            # TPU tiling rule (second-minor dim neither 8-aligned nor full)
+            pl.BlockSpec((1, 1, bs),
+                         lambda bi, ji, bt, ps: (bt[bi, ji], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, hd), lambda bi, ji, bt, ps: (bi, 0, 0)),
         scratch_shapes=[
@@ -131,4 +134,4 @@ def paged_attention(q, k_pool, v_pool, kpos_pool, block_table, pos, *,
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         interpret=interpret,
     )(jnp.asarray(block_table, jnp.int32), jnp.asarray(pos, jnp.int32),
-      q, k_pool, v_pool, kpos_pool)
+      q, k_pool, v_pool, kpos_pool.reshape(-1, 1, bs))

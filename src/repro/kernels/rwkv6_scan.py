@@ -36,6 +36,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _dot(a, b, contract=((1,), (0,))):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sfin_ref,
             s_ref, *, nc: int, chunk: int):
     ci = pl.program_id(1)
@@ -48,38 +54,42 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sfin_ref,
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
-    u = u_ref[...].astype(jnp.float32)          # (hd,)
+    u = u_ref[...].astype(jnp.float32)          # (1, hd)
     s = s_ref[...]                              # (hd, hd)
+    hd = s.shape[0]
 
+    # Everything below stays 2-D (or 3-D with the lane axis last) and
+    # uses no cumsum, which Mosaic cannot lower: the prefix sums and the
+    # diagonal decay of the state are matmuls at HIGHEST precision
+    # (their rounding errors would otherwise be exponentiated).
     logw = jnp.log(jnp.maximum(w, 1e-30))
-    big_l = jnp.cumsum(logw, axis=0)            # (T, hd): L_t (1-based)
-    l_prev = big_l - logw                       # L_{t-1}
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    big_l = _dot((t_idx >= s_idx).astype(jnp.float32), logw)   # L_t (1-based)
+    l_prev = big_l - logw                                       # L_{t-1}
 
     # cross-chunk contribution (decayed state read)
-    r_dec = r * jnp.exp(l_prev)
-    cross = jax.lax.dot_general(r_dec, s, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    cross = _dot(r * jnp.exp(l_prev), s)
 
     # intra-chunk: P[t,tau] = sum_i r[t,i] k[tau,i] exp(L_{t-1,i}-L_{tau,i})
     diff = l_prev[:, None, :] - big_l[None, :, :]        # (T, T, hd), <= 0 on tau<t
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tri = t_idx > s_idx                                   # strict lower triangle
-    decay = jnp.where(tri[..., None], jnp.exp(diff), 0.0)
+    tri = jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0) > \
+        jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1)   # strict lower
+    decay = jnp.where(tri, jnp.exp(diff), 0.0)
     p = jnp.sum(r[:, None, :] * k[None, :, :] * decay, axis=-1)   # (T, T)
-    intra = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    intra = _dot(p, v)
 
     # self (bonus) term
-    rku = jnp.sum(r * u[None, :] * k, axis=-1)            # (T,)
-    o_ref[...] = (cross + intra + rku[:, None] * v).astype(o_ref.dtype)
+    rku = jnp.sum(r * u * k, axis=-1, keepdims=True)     # (T, 1)
+    o_ref[...] = (cross + intra + rku * v).astype(o_ref.dtype)
 
     # state update: S' = diag(exp(L_T)) S + (k * exp(L_T - L))^T @ v
-    l_tot = big_l[-1]                                     # (hd,)
-    k_dec = k * jnp.exp(l_tot[None, :] - big_l)
-    s_new = jnp.exp(l_tot)[:, None] * s + jax.lax.dot_general(
-        k_dec, v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    l_tot = big_l[chunk - 1:chunk, :]                     # (1, hd)
+    k_dec = k * jnp.exp(l_tot - big_l)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1)
+    diag = jnp.where(row == col, jnp.exp(l_tot), 0.0)    # diag(exp(L_T))
+    s_new = _dot(diag, s) + _dot(k_dec, v, ((0,), (0,)))
     s_ref[...] = s_new
 
     @pl.when(ci == nc - 1)
@@ -111,7 +121,9 @@ def rwkv6_scan(r, k, v, w, u, s0=None, *, chunk: int = 32,
     kf = k.reshape(bh, sp, hd)
     vf = v.reshape(bh, sp, hd)
     wf = w.reshape(bh, sp, hd)
-    uf = jnp.broadcast_to(u[None], (b, h, hd)).reshape(bh, hd)
+    # (bh, 1, hd): a squeezed-row (hd,) block over (bh, hd) breaks the
+    # TPU tiling rule (second-minor dim neither 8-aligned nor full)
+    uf = jnp.broadcast_to(u[None], (b, h, hd)).reshape(bh, 1, hd)
     s0f = s0.reshape(bh, hd, hd)
 
     kern = functools.partial(_kernel, nc=nc, chunk=chunk)
@@ -123,7 +135,7 @@ def rwkv6_scan(r, k, v, w, u, s0=None, *, chunk: int = 32,
             pl.BlockSpec((None, chunk, hd), lambda i, c: (i, c, 0)),
             pl.BlockSpec((None, chunk, hd), lambda i, c: (i, c, 0)),
             pl.BlockSpec((None, chunk, hd), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((None, hd), lambda i, c: (i, 0)),
+            pl.BlockSpec((None, 1, hd), lambda i, c: (i, 0, 0)),
             pl.BlockSpec((None, hd, hd), lambda i, c: (i, 0, 0)),
         ],
         out_specs=[

@@ -11,23 +11,56 @@ reduction only.
 """
 from __future__ import annotations
 
-import jax
+import dataclasses
 
-# TPU v5e hardware constants (per chip) for the roofline model.
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # B/s
-ICI_BW = 50e9                  # B/s per link
-ICI_LINKS = 4                  # v5e: 4 ICI links per chip (2D torus x2)
+import jax
+from jax.sharding import AxisType
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks for the roofline model."""
+    flops_bf16: float          # FLOP/s
+    hbm_bw: float              # B/s
+    ici_bw: float              # B/s per link
+    ici_links: int
+
+
+# Keyed by ``jax.devices()[0].device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# of inter-chip interconnect = 4 links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9,
+                             ici_bw=50e9, ici_links=4),
+}
+
+# The chip the production meshes below (and the dry-run) are built for.
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a chip not in the table is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+def _mesh(shape, axes):
+    # Auto axes: the model's logical-axis ``shard()`` constraints assume
+    # them (newer JAX defaults ``make_mesh`` to Explicit axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Whatever-fits mesh for CPU tests/examples (1 device -> (1, 1))."""
     n = len(jax.devices())
     dp = max(n // model_parallel, 1)
-    return jax.make_mesh((dp, model_parallel), ("data", "model"))
+    return _mesh((dp, model_parallel), ("data", "model"))

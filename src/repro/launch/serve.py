@@ -1,5 +1,6 @@
 """Serving launcher: continuous-batching LLM inference on any assigned
-architecture (reduced variants on the CPU container).
+architecture (reduced variants by default, for CPU runs; ``--no-reduced``
+serves the published widths, e.g. on a TPU).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
         --engine paged --requests 8 --max-new 16
@@ -48,6 +49,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.api import Model
 from repro.obs import Observability
 from repro.serving.cluster import Rejected, ServingCluster
@@ -134,10 +136,13 @@ def build_engine(args, model, params, obs=None):
                      cache_max=args.cache_max, obs=obs)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="CPU-smoke widths (default); --no-reduced builds "
+                         "the published widths")
     ap.add_argument("--engine", choices=("paged", "slot"), default=None,
                     help="default: paged when the arch supports it")
     ap.add_argument("--requests", type=int, default=8)
@@ -209,8 +214,13 @@ def main():
                     help="write a Chrome trace-event JSON of the run "
                          "(open in Perfetto)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
+
+def load_model(args):
+    """-> (cfg, model, params) for the parsed CLI ``args``; resolves the
+    default ``--engine`` for the arch.  Weights are random from
+    ``--seed``."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -221,6 +231,13 @@ def main():
     if args.engine is None:
         args.engine = "paged" if model.supports_paged else "slot"
     params = model.init(jax.random.PRNGKey(args.seed))
+    return cfg, model, params
+
+
+def main():
+    args = build_parser().parse_args()
+    configure_compile_cache()
+    cfg, model, params = load_model(args)
     if args.replicas > 1:
         if args.engine != "paged":
             raise SystemExit("--replicas needs the paged engine")

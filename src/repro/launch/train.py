@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.core.trainer import make_train_step
 from repro.data.tokens import make_stream
 from repro.models import frontend as fe
@@ -29,7 +30,10 @@ from repro.optim import adamw, cosine_warmup
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="CPU-smoke widths (default); --no-reduced builds "
+                         "the published widths")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -38,6 +42,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

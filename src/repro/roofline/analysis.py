@@ -1,8 +1,10 @@
-"""Roofline analysis from compiled XLA artifacts (TPU v5e constants).
+"""Roofline analysis from compiled XLA artifacts.
 
 Three terms per (arch x shape x mesh), all PER DEVICE (the compiled SPMD
 module is the per-device program, so cost_analysis numbers and HLO shapes
-are already local):
+are already local), against the published peaks of the chip named by
+``device_kind`` (``launch/mesh.py:PEAKS``; default: the production
+meshes' TPU v5e; a kind without published peaks raises):
 
     compute_s    = HLO_FLOPs / PEAK_FLOPS
     memory_s     = HLO_bytes / HBM_BW
@@ -33,7 +35,7 @@ from typing import Dict, Tuple
 
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import InputShape
-from repro.launch.mesh import HBM_BW, ICI_BW, ICI_LINKS, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, ChipPeaks, peaks_for
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -90,18 +92,24 @@ class RooflineResult:
     hbm_bytes: float             # per device
     coll_bytes_weighted: float   # per device
     coll_by_kind: Dict[str, float]
+    device_kind: str = TARGET_KIND
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return peaks_for(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes_weighted / (ICI_BW * ICI_LINKS)
+        return self.coll_bytes_weighted / (self.peaks.ici_bw *
+                                           self.peaks.ici_links)
 
     @property
     def dominant(self) -> str:
@@ -114,10 +122,11 @@ class RooflineResult:
                 "collective_s": self.collective_s, "dominant": self.dominant}
 
 
-def roofline_terms(flops: float, hbm_bytes: float, hlo_text: str
-                   ) -> RooflineResult:
+def roofline_terms(flops: float, hbm_bytes: float, hlo_text: str,
+                   device_kind: str = TARGET_KIND) -> RooflineResult:
+    peaks_for(device_kind)                  # unknown chip: raise here
     w, kinds = collective_bytes(hlo_text)
-    return RooflineResult(flops, hbm_bytes, w, kinds)
+    return RooflineResult(flops, hbm_bytes, w, kinds, device_kind)
 
 
 # ---------------------------------------------------------------- analytic

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from conftest import reduced_cfg
 from repro.configs.base import get_config
@@ -23,7 +24,8 @@ TINY_DECODE = InputShape("d", 64, 4, "decode")
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 @pytest.mark.parametrize("shape", [TINY_TRAIN, TINY_PREFILL, TINY_DECODE],
@@ -66,7 +68,8 @@ def test_rule_tables_complete(variant, mode, mesh):
 
 
 def test_variant_changes_param_sharding():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     model = Model(reduced_cfg("qwen3-0.6b"))
     tp = model.param_pspecs(make_rules(mesh, "train", "tp"))
     fsdp = model.param_pspecs(make_rules(mesh, "train", "fsdp"))

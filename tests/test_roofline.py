@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.base import get_config
 from repro.configs.shapes import InputShape
@@ -48,6 +49,15 @@ def test_roofline_terms_dominance():
     assert r2.dominant == "memory"
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the device-kind table; a chip without published
+    peaks is an error, never a silent v5e default."""
+    r = roofline_terms(197e12, 0.0, "", device_kind="TPU v5 lite")
+    assert r.compute_s == pytest.approx(1.0)
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline_terms(1e12, 1e9, "", device_kind="cpu")
+
+
 def test_model_flops_modes():
     cfg = get_config("qwen3-0.6b")
     train = InputShape("t", 1024, 8, "train")
@@ -74,7 +84,8 @@ def test_compositional_assembly_matches_unscanned_compile():
         get_config("qwen3-0.6b").reduced(), num_layers=2, dtype="float32")
     model = Model(cfg)
     shape = InputShape("tiny_train", 64, 4, "train")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
     compiled, _ = lower_step(model, shape, mesh, "tp")
     full = _cost(compiled)
