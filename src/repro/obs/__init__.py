@@ -1,9 +1,11 @@
-"""Dependency-free observability subsystem: metrics + tracing.
+"""Observability subsystem: metrics + tracing, with no dependency
+beyond ``jax.profiler``'s annotations for the engine's step phases.
 
 ``Observability`` is the bundle the serving stack threads around — a
 ``MetricsRegistry`` (always) plus an optional ``TraceRecorder``.  The
 engines wrap it in ``EngineObs`` (``obs/engine.py``) so the step loop
-pays one attribute check when instrumentation is off.
+pays one attribute check, and a shared no-op context per step phase,
+when instrumentation is off.
 
     from repro.obs import Observability
     obs = Observability.create(trace=True)          # wall-clock trace
@@ -11,6 +13,9 @@ pays one attribute check when instrumentation is off.
     ...
     print(obs.metrics.render())                     # Prometheus text
     obs.trace.export("trace.json")                  # open in Perfetto
+
+The step-phase spans (``step.schedule`` ... ``step.account``, ``gc``)
+land in a ``jax.profiler`` trace when one is recording.
 """
 from __future__ import annotations
 
@@ -33,15 +38,28 @@ __all__ = [
 
 @dataclasses.dataclass
 class Observability:
-    """Metrics registry + optional trace recorder, passed as one unit."""
+    """Metrics registry + optional trace recorder, passed as one unit.
+
+    ``mode`` is the caller's clock: ``"wall"`` (seconds; results are
+    stamped when they reached the host) or ``"sim"`` (a logical clock;
+    results keep the step's ``now``).  It defaults to the trace's mode,
+    else ``"wall"``."""
 
     metrics: MetricsRegistry
     trace: Optional[TraceRecorder] = None
+    mode: Optional[str] = None
+
+    def __post_init__(self):
+        if self.mode is None:
+            self.mode = self.trace.mode if self.trace else "wall"
 
     @classmethod
     def create(cls, trace: bool = False,
                trace_mode: str = "wall") -> "Observability":
-        """``trace_mode="sim"`` zeroes measured wall durations so
-        exports under the discrete-event clock are deterministic."""
+        """``trace_mode="sim"`` (a discrete-event or step clock) zeroes
+        measured wall durations so exports are deterministic, and keeps
+        result stamps on the caller's ``now``, with or without a
+        trace."""
         return cls(MetricsRegistry(),
-                   TraceRecorder(mode=trace_mode) if trace else None)
+                   TraceRecorder(mode=trace_mode) if trace else None,
+                   trace_mode)

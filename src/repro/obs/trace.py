@@ -12,7 +12,8 @@ Perfetto (https://ui.perfetto.dev — "Open trace file") or
     scheduler evicts and re-admits;
   * **engine steps** (pid 0): one ``X`` (complete) event per
     ``engine.step()`` carrying admissions, chunk tokens drained, decode
-    batch size, tokens written, dispatch wall time, and a retrace flag,
+    batch size, tokens written, dispatch wall time, the wall time of
+    each step phase (``phase_ms``, paged engine), and a retrace flag,
     plus a ``C`` counter track of queue/pool occupancy.
 
 Timestamp modes: events are stamped with whatever clock the caller
@@ -68,13 +69,20 @@ class TraceRecorder:
         """Instant lifecycle event on the request's own track."""
         self._events.append(("i", phase, ts, PID_REQUESTS, rid, 0.0, args))
 
-    def step(self, ts: float, wall_s: float, **args) -> None:
+    def step(self, ts: float, wall_s: float,
+             phases: Optional[Dict[str, float]] = None, **args) -> None:
         """One engine step: ``X`` complete event on the engine track.
         ``ts`` is the step's (caller-clock) start; ``wall_s`` the
-        measured dispatch wall time (zeroed in sim mode so exports stay
-        deterministic — it still rides along in args as ``wall_ms``)."""
+        measured dispatch wall time, carried in args as ``wall_ms`` in
+        wall mode and zeroed in sim mode so exports stay deterministic.
+        ``phases`` (phase -> wall seconds) rides along as ``phase_ms``,
+        with every value 0 in sim mode."""
         if self.mode == "wall":
             args["wall_ms"] = round(wall_s * 1e3, 3)
+        if phases is not None:
+            args["phase_ms"] = {
+                k: round(v * 1e3, 3) if self.mode == "wall" else 0.0
+                for k, v in phases.items()}
         dur = wall_s if self.mode == "wall" else 0.0
         self._events.append(("X", "step", ts, PID_ENGINE, 0, dur, args))
 

@@ -145,8 +145,12 @@ class ServingCluster:
         """(Re-)bind a fresh per-replica ``Observability`` bundle to
         every engine, replica-labeled.  Benchmarks call this between
         the cold (compile-inclusive) and warm measured passes so the
-        merged histograms cover exactly one pass."""
-        self.replica_obs = [Observability.create() for _ in self.engines]
+        merged histograms cover exactly one pass.  The bundles take
+        ``step(now)`` as a logical clock (``mode="sim"``): latencies
+        are differences of the callers' ``now``s, deterministic on a
+        step clock."""
+        self.replica_obs = [Observability.create(trace_mode="sim")
+                            for _ in self.engines]
         for i, (e, o) in enumerate(zip(self.engines, self.replica_obs)):
             e.attach_obs(o, replica=i)
 

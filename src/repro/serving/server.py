@@ -19,6 +19,7 @@ docstring for the policy).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Dict, List, Optional
@@ -212,6 +213,13 @@ class StratusApp:
 
 @dataclasses.dataclass
 class GenRequest:
+    """One generation request.  ``first_token_at``, ``last_token_at``
+    and ``finished_at`` are the ``now`` of the step that made the token
+    or finished the request (the step's start on the caller's clock:
+    the cluster tier's virtual clock and the benchmarks' TTFT read
+    them); obs events stamp results when they reached the host instead
+    (``EngineObs.ready``)."""
+
     rid: int
     prompt: np.ndarray                 # (S,) int32
     max_new: int
@@ -222,10 +230,20 @@ class GenRequest:
     finished_at: Optional[float] = None
 
 
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_phase(_name):
+    return _NO_SPAN
+
+
 class _EngineObsMixin:
     """Shared instrumentation plumbing for both engines: an optional
     ``EngineObs`` facade plus per-token timestamp tracking that feeds
-    the TTFT / inter-token histograms."""
+    the TTFT / inter-token histograms.  ``self._phase(name)`` spans one
+    phase of a step (``EngineObs.phase``); with no obs it is a shared
+    no-op context, so the step builds no annotation and reads no
+    clock."""
 
     obs: Optional[EngineObs] = None
     _engine_kind = "slot"
@@ -238,21 +256,29 @@ class _EngineObsMixin:
         to the engine metrics (cluster tier) — request latency
         histograms stay unlabeled either way so replica snapshots
         merge into one fleet-wide distribution."""
+        if self.obs is not None:
+            self.obs.close()
         self.obs = EngineObs(obs, self._engine_kind, replica) \
             if obs is not None else None
+        self._phase = self.obs.phase if self.obs else _no_phase
 
     def _note_token(self, req: GenRequest, now: float) -> None:
-        """One output token emitted for ``req`` at ``now``: track the
-        first/last token timestamps and feed the TTFT and inter-token
-        histograms (``first_token_at`` also drives benchmark TTFT)."""
-        if req.first_token_at is None:
+        """One output token emitted for ``req`` by the step at ``now``:
+        track the first/last token timestamps and feed the TTFT and
+        inter-token histograms.  ``first_token_at`` stays the step's
+        ``now``; the histograms and obs events take ``obs.ready``, when
+        the ids reached the host."""
+        first = req.first_token_at is None
+        if first:
             req.first_token_at = now
-            if self.obs:
+        if self.obs:
+            now = self.obs.ready
+            if first:
                 self.obs.first_token(req.rid, now, now - req.submitted)
-        elif self.obs:
-            gap = None if req.last_token_at is None \
-                else now - req.last_token_at
-            self.obs.token(req.rid, now, gap)
+            else:
+                gap = None if req.last_token_at is None \
+                    else now - req.last_token_at
+                self.obs.token(req.rid, now, gap)
         req.last_token_at = now
 
 
@@ -310,6 +336,7 @@ class LLMEngine(_EngineObsMixin):
         if self.obs is None:
             return self._step(now)
         t0 = time.perf_counter()
+        self.obs.begin_step(now, t0)
         pre = (self.admissions, self.prefill_tokens, self.generated_tokens,
                len(self._prefill_sigs) + len(self._decode_sigs))
         self._decode_batch_last = 0
@@ -391,7 +418,8 @@ class LLMEngine(_EngineObsMixin):
                 self.pos[s] = -1
                 self.finished_count += 1
                 if self.obs:
-                    self.obs.finished(req.rid, now, now - req.submitted,
+                    at = self.obs.ready
+                    self.obs.finished(req.rid, at, at - req.submitted,
                                       len(req.out_tokens))
         return done
 
@@ -434,6 +462,11 @@ class _PrefillState:
     all_blocks: List[int]
     start: int
     done: int
+
+    def completes(self, take: int) -> bool:
+        """A chunk of ``take`` lanes from ``done`` ends the sequence, so
+        the dispatch's ids hold the first output token."""
+        return self.done + take == len(self.seq)
 
 
 class PagedLLMEngine(_EngineObsMixin):
@@ -957,6 +990,7 @@ class PagedLLMEngine(_EngineObsMixin):
         if self.obs is None:
             return self._step(now)
         t0 = time.perf_counter()
+        self.obs.begin_step(now, t0)
         pre = (self.admissions, self.prefill_tokens, self.generated_tokens,
                len(self._prefill_sigs) + len(self._decode_sigs))
         self._decode_batch_last = 0
@@ -977,21 +1011,32 @@ class PagedLLMEngine(_EngineObsMixin):
         return done
 
     def _step(self, now: float) -> List[GenRequest]:
-        self._admit_all(now)
+        """Phases (``EngineObs.phase``): schedule (admission, chunk and
+        verify planning, block growth, copy-on-write, preemption) ->
+        prepare (the dispatch's host arrays and their transfers) ->
+        launch (the jitted call, until it returns) -> wait (the host
+        blocked on the device's ids) -> account (cursors, acceptance,
+        rollback, finishing).  The unfused path runs up to two
+        dispatches a step and spans each the same way."""
         if self._fused_decode:
             return self._spec_step(now)
+        ph = self._phase
+        with ph("schedule"):
+            self._admit_all(now)
         done: List[GenRequest] = []
         prefilled = bool(self.prefilling)
         if self.prefilling:
             self._prefill_chunks(now)
             # requests satisfied at prefill (max_new == 1 / max_len edge)
             # must leave before the decode below hands them another token
-            done = self._collect(now)
+            with ph("account"):
+                done = self._collect(now)
         if self.scheduler == "serial" and prefilled:
             return done
         if self.active:
             return done + self._decode_all(now)
-        return done + self._collect(now)
+        with ph("account"):
+            return done + self._collect(now)
 
     def _admit_all(self, now: float) -> None:
         """Admit queued requests.  The continuous scheduler scans an
@@ -1144,6 +1189,20 @@ class PagedLLMEngine(_EngineObsMixin):
         ``rows``) maps hybrid-stack dispatch rows to their recurrent
         state slots; padding rows route to the trash slot (index
         ``max_batch``)."""
+        ph = self._phase
+        with ph("prepare"):
+            toks, args = self._ragged_inputs(rows, state_rows, all_logits)
+        fn = self._prefill_verify if all_logits else self._prefill_paged
+        with ph("launch"):
+            out, self.pools = fn(self.params, {"tokens": toks}, self.pools,
+                                 *args)
+        return out
+
+    def _ragged_inputs(self, rows: List[tuple], state_rows,
+                       all_logits: bool) -> tuple:
+        """Host side of ``_ragged_dispatch``: the padded tokens, and the
+        table, cursors, lengths and state rows moved to the device.
+        -> (tokens, (table, starts, lens, state rows, c_pad))."""
         r_pad = self._bucket_rows(len(rows))
         # decode-only fused steps are all length-1 windows: dispatch at
         # c_pad=1 instead of padding every lane up to the first length
@@ -1166,12 +1225,8 @@ class PagedLLMEngine(_EngineObsMixin):
         if state_rows is not None:
             srows[:len(state_rows)] = state_rows
         self._prefill_sigs.add((r_pad, c_pad, nb_pad, all_logits))
-        fn = self._prefill_verify if all_logits else self._prefill_paged
-        out, self.pools = fn(
-            self.params, {"tokens": toks}, self.pools, jnp.asarray(bt),
-            jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(srows),
-            c_pad)
-        return out
+        return toks, (jnp.asarray(bt), jnp.asarray(starts),
+                      jnp.asarray(lens), jnp.asarray(srows), c_pad)
 
     def _chunk_rows(self, sel: List[tuple]) -> List[tuple]:
         return [(self.prefilling[r].seq[self.prefilling[r].done:
@@ -1188,31 +1243,34 @@ class PagedLLMEngine(_EngineObsMixin):
             st = self.prefilling[r]
             if self.obs:
                 self.obs.prefill_chunk(st.req.rid, now, st.done, take)
+            last = st.completes(take)
             st.done += take
             self.prefill_tokens += take
             # window stacks: blocks the chunk just slid out of the live
             # window die immediately (next query position = st.done)
             self._window_shrink(st.all_blocks, st.done)
-            if st.done == len(st.seq):
+            if last:
                 self._finish_prefill(r, tok_at(i, take), now)
 
     def _prefill_chunks(self, now: float) -> None:
         """Advance every pending prefill by up to one chunk in ONE
         ragged bucketed dispatch (unfused path: serial scheduler or
         ``decode_fusion=False``; fused mode carries chunks in the
-        verify dispatch in ``_spec_step``)."""
-        sel, _ = self._select_chunks()
-        logits = self._ragged_dispatch(self._chunk_rows(sel),
-                                       [r for r, _ in sel],
+        verify dispatch in ``_spec_step``).  Logits come to the host
+        only when a chunk completes its prompt."""
+        ph = self._phase
+        with ph("schedule"):
+            sel, _ = self._select_chunks()
+            rows = self._chunk_rows(sel)
+            completes = any(self.prefilling[r].completes(take)
+                            for r, take in sel)
+        logits = self._ragged_dispatch(rows, [r for r, _ in sel],
                                        all_logits=False)
-        arr: List = [None]
-
-        def tok_at(i, take):
-            if arr[0] is None:
-                arr[0] = np.asarray(logits)
-            return int(np.argmax(arr[0][i, 0]))
-
-        self._account_chunks(sel, tok_at, now)
+        with ph("wait"):
+            arr = np.asarray(logits) if completes else None
+        with ph("account"):
+            self._account_chunks(
+                sel, lambda i, take: int(np.argmax(arr[i, 0])), now)
 
     # ------------------------------------------------------------ spec
     def _spec_step(self, now: float) -> List[GenRequest]:
@@ -1231,33 +1289,39 @@ class PagedLLMEngine(_EngineObsMixin):
         one-token window, so decode advances every step regardless.
         The per-token decode kernel is idle in spec mode — verify rows
         replace the decode dispatch entirely."""
-        sel, budget_left = self._select_chunks()
-        verify = self._plan_verify(budget_left, now)
-        # planning may preempt (growth under a dry pool) — drop entries
-        # whose row was reclaimed
-        sel = [(r, t) for r, t in sel if r in self.prefilling]
-        verify = [(r, w) for r, w in verify if r in self.active]
-        if not sel and not verify:
-            return self._collect(now)
-        rows = self._chunk_rows(sel) + [
-            (np.asarray(w, np.int32), int(self.pos[r]), self.row_blocks[r])
-            for r, w in verify]
+        ph = self._phase
+        with ph("schedule"):
+            self._admit_all(now)
+            sel, budget_left = self._select_chunks()
+            verify = self._plan_verify(budget_left, now)
+            # planning may preempt (growth under a dry pool) — drop
+            # entries whose row was reclaimed
+            sel = [(r, t) for r, t in sel if r in self.prefilling]
+            verify = [(r, w) for r, w in verify if r in self.active]
+            rows = self._chunk_rows(sel) + [
+                (np.asarray(w, np.int32), int(self.pos[r]),
+                 self.row_blocks[r]) for r, w in verify]
+        if not rows:
+            with ph("account"):
+                return self._collect(now)
         self._decode_batch_last = len(verify)
         srows = [r for r, _ in sel] + [r for r, _ in verify]
         greedy = self._ragged_dispatch(rows, srows, all_logits=True)
-        arr = np.asarray(greedy)                  # (r_pad, c_pad) tokens
-        nchunk = len(sel)
-        self._account_chunks(sel, lambda i, take: int(arr[i, take - 1]),
-                             now)
-        stale_b, stale_l = [], []
-        for j, (row, window) in enumerate(verify):
-            self._accept_verify(row, window, arr[nchunk + j], now,
-                                stale_b, stale_l)
-        if stale_b:
-            self.pools = invalidate_lanes(self.pools,
-                                          np.concatenate(stale_b),
-                                          np.concatenate(stale_l))
-        return self._collect(now)
+        with ph("wait"):
+            arr = np.asarray(greedy)              # (r_pad, c_pad) tokens
+        with ph("account"):
+            nchunk = len(sel)
+            self._account_chunks(
+                sel, lambda i, take: int(arr[i, take - 1]), now)
+            stale_b, stale_l = [], []
+            for j, (row, window) in enumerate(verify):
+                self._accept_verify(row, window, arr[nchunk + j], now,
+                                    stale_b, stale_l)
+            if stale_b:
+                self.pools = invalidate_lanes(self.pools,
+                                              np.concatenate(stale_b),
+                                              np.concatenate(stale_l))
+            return self._collect(now)
 
     def _plan_verify(self, budget: int, now: float) -> List[tuple]:
         """Build this step's verify windows: for every decoding row
@@ -1397,8 +1461,9 @@ class PagedLLMEngine(_EngineObsMixin):
             stale_l.append((p % self.block_size).astype(np.int32))
         self._window_shrink(blocks, P + m, self.block_table[row])
         if self.obs and self.drafter is not None:
-            self.obs.spec_verify(req.rid, now, proposed=take - 1,
-                                 accepted=a, emitted=m, rolled_back=rolled)
+            self.obs.spec_verify(req.rid, self.obs.ready,
+                                 proposed=take - 1, accepted=a, emitted=m,
+                                 rolled_back=rolled)
 
     def _finish_prefill(self, row: int, tok: int, now: float) -> None:
         """Last chunk spliced: emit the first token and move the row to
@@ -1439,9 +1504,44 @@ class PagedLLMEngine(_EngineObsMixin):
             self.obs.preempted(req.rid, now, where)
 
     def _decode_all(self, now: float) -> List[GenRequest]:
+        ph = self._phase
         # grow block tables for the next write, oldest request first;
         # evict cold cached blocks, then preempt the youngest, instead
         # of failing when the pool is dry.
+        with ph("schedule"):
+            self._grow_decode_blocks(now)
+        if not self.active:
+            return []
+
+        with ph("prepare"):
+            tokens = np.zeros((self.max_batch, 1), np.int32)
+            pos = np.zeros((self.max_batch,), np.int32)
+            active_mask = np.zeros((self.max_batch,), bool)
+            for row, req in self.active.items():
+                tokens[row, 0] = req.out_tokens[-1]
+                pos[row] = self.pos[row]
+                active_mask[row] = True
+            self._decode_sigs.add((self.max_batch, self.nb_max))
+            self._decode_batch_last = len(self.active)
+            args = (jnp.asarray(self.block_table), jnp.asarray(tokens),
+                    jnp.asarray(pos), jnp.asarray(active_mask))
+        with ph("launch"):
+            logits, self.pools = self._decode(self.params, self.pools,
+                                              *args)
+        with ph("wait"):
+            arr = np.asarray(logits)
+        with ph("account"):
+            for row, req in self.active.items():
+                req.out_tokens.append(int(np.argmax(arr[row, 0])))
+                self.generated_tokens += 1
+                self._note_token(req, now)
+                self.pos[row] += 1
+                self._window_shrink(self.row_blocks[row],
+                                    int(self.pos[row]),
+                                    self.block_table[row])
+            return self._collect(now)
+
+    def _grow_decode_blocks(self, now: float) -> None:
         for row in sorted(self.active, key=lambda r: self.active[r].rid):
             while row in self.active and \
                     int(self.pos[row]) // self.block_size >= \
@@ -1457,30 +1557,6 @@ class PagedLLMEngine(_EngineObsMixin):
                         f"{self.allocator.num_usable} usable blocks")
                 else:
                     self._preempt_youngest(now)
-        if not self.active:
-            return []
-
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch,), np.int32)
-        active_mask = np.zeros((self.max_batch,), bool)
-        for row, req in self.active.items():
-            tokens[row, 0] = req.out_tokens[-1]
-            pos[row] = self.pos[row]
-            active_mask[row] = True
-        self._decode_sigs.add((self.max_batch, self.nb_max))
-        self._decode_batch_last = len(self.active)
-        logits, self.pools = self._decode(
-            self.params, self.pools, jnp.asarray(self.block_table),
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active_mask))
-        arr = np.asarray(logits)
-        for row, req in self.active.items():
-            req.out_tokens.append(int(np.argmax(arr[row, 0])))
-            self.generated_tokens += 1
-            self._note_token(req, now)
-            self.pos[row] += 1
-            self._window_shrink(self.row_blocks[row], int(self.pos[row]),
-                                self.block_table[row])
-        return self._collect(now)
 
     def _collect(self, now: float) -> List[GenRequest]:
         done = []
@@ -1510,6 +1586,7 @@ class PagedLLMEngine(_EngineObsMixin):
                 self.pos[row] = 0
                 self.finished_count += 1
                 if self.obs:
-                    self.obs.finished(req.rid, now, now - req.submitted,
+                    at = self.obs.ready
+                    self.obs.finished(req.rid, at, at - req.submitted,
                                       len(req.out_tokens))
         return done

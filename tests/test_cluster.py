@@ -266,6 +266,27 @@ def test_merged_metrics_exact(qwen_model, workload):
     assert f'replica="1"' in merged.render()
 
 
+def test_cluster_latencies_count_whole_steps(qwen_model, workload):
+    """The cluster's metrics-only bundles take ``step(now)`` as a
+    logical clock: driven on whole steps, every TTFT, gap and e2e
+    observation is a whole number of steps (no wall seconds added)."""
+    model, params = qwen_model
+    cl = ServingCluster(_make(model, params), 2, seed=0, obs=True)
+    for p, n in zip(workload.prompts[:6], workload.max_news[:6]):
+        cl.submit(p, max_new=n, now=0.0)
+    k = 0
+    while not cl.idle:
+        k += 1
+        cl.step(now=float(k))
+    merged = cl.merged_metrics()
+    for name in ("request_ttft_seconds", "request_intertoken_seconds",
+                 "request_e2e_seconds"):
+        h = merged.histogram(name)
+        assert h.count > 0
+        assert h.sum == int(h.sum), name
+    assert merged.histogram("request_ttft_seconds").sum >= 6
+
+
 # ------------------------------------------ broker routing property test
 
 

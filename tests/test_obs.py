@@ -11,8 +11,12 @@ The load-bearing contracts:
     (including copy-on-write), and preemption-resume;
   * both engines' ``stats()`` dicts carry exactly the keys
     ``serving/stats_schema.py`` declares (the schema IS the test);
-  * attaching instrumentation never changes an output token.
+  * attaching instrumentation never changes an output token;
+  * the paged step's phases (``step.schedule`` ... ``step.account``)
+    are timed into every step event and ``engine_phase_seconds_total``,
+    and with no obs attached the step opens no span and hooks no gc.
 """
+import gc
 import json
 
 import numpy as np
@@ -24,6 +28,7 @@ from repro.models.api import Model
 from repro.obs import (DEFAULT_BUCKETS, EngineObs, Histogram,
                        MetricsRegistry, Observability, TraceRecorder,
                        summarize_latencies, validate_chrome_trace)
+from repro.obs.engine import GC_SPANS, PHASES
 from repro.obs.trace import span_report
 from repro.serving.server import LLMEngine, PagedLLMEngine
 from repro.serving.stats_schema import validate
@@ -452,6 +457,135 @@ def test_two_engines_share_one_registry(qwen_model):
                                labels={"engine": "slot"}).value == 1
     # the unlabeled request histograms pool across engines
     assert obs.metrics.histogram("request_e2e_seconds").count == 2
+
+
+# ---------------------------------------------------------- step phases
+
+
+def _phase_engine(model, params, obs, fused=True):
+    eng = PagedLLMEngine(model, params, num_blocks=64, block_size=8,
+                         max_batch=4, max_len=64, prefill_chunk=8,
+                         decode_fusion=fused, obs=obs)
+    rng = np.random.default_rng(4)
+    for n in (20, 7, 13):
+        eng.submit(rng.integers(1, model.cfg.vocab_size, n)
+                   .astype(np.int32), max_new=4)
+    return eng
+
+
+def _step_events(obs):
+    return [e for e in obs.trace.to_chrome()["traceEvents"]
+            if e["ph"] == "X" and e["name"] == "step"]
+
+
+@pytest.mark.parametrize("mode,fused", [("wall", True), ("wall", False),
+                                        ("sim", True)])
+def test_step_events_carry_phase_ms(qwen_model, mode, fused):
+    """Every paged step event carries the five phases' wall times; in
+    wall mode they fit inside the step's own wall time and add up to
+    ``engine_phase_seconds_total``; in sim mode they are 0."""
+    model, params = qwen_model
+    obs = Observability.create(trace=True, trace_mode=mode)
+    _drain(_phase_engine(model, params, obs, fused), now_step=1.0)
+    steps = _step_events(obs)
+    assert steps
+    for ev in steps:
+        ph = ev["args"]["phase_ms"]
+        assert tuple(ph) == PHASES
+        if mode == "sim":
+            assert set(ph.values()) == {0.0}
+        else:
+            assert sum(ph.values()) <= ev["args"]["wall_ms"] + 0.01
+    lab = {"engine": "paged"}
+    totals = {p: obs.metrics.counter("engine_phase_seconds_total",
+                                     labels={**lab, "phase": p}).value
+              for p in PHASES}
+    assert all(totals[p] > 0 for p in PHASES)
+    assert 'engine_phase_seconds_total{engine="paged",phase="wait"}' \
+        in obs.metrics.render()
+    if mode == "wall":
+        for p in PHASES:
+            assert totals[p] == pytest.approx(
+                sum(e["args"]["phase_ms"][p] for e in steps) / 1e3,
+                abs=1e-6 * len(steps))
+
+
+def test_sim_trace_stays_byte_stable_with_phases(qwen_model):
+    model, params = qwen_model
+
+    def export():
+        obs = Observability.create(trace=True, trace_mode="sim")
+        _drain(_phase_engine(model, params, obs), now_step=0.5)
+        return json.dumps(obs.trace.to_chrome())
+
+    assert export() == export()
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``; records names."""
+    made: list = []
+
+    def __init__(self, name, **kw):
+        self.made.append((name, kw))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_no_obs_opens_no_span_and_hooks_no_gc(qwen_model, monkeypatch):
+    import jax
+
+    model, params = qwen_model
+    made = []
+    monkeypatch.setattr(_Annotations, "made", made)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotations)
+    before = list(gc.callbacks)
+    eng = _phase_engine(model, params, None)
+    _drain(eng)
+    assert not [n for n, _ in made if n.startswith("step.")]
+    assert gc.callbacks == before
+    # attached: the same patch point sees every phase, tagged with the
+    # step number, and gc collections become spans
+    eng.attach_obs(Observability.create())
+    assert GC_SPANS in gc.callbacks
+    rng = np.random.default_rng(5)
+    eng.submit(rng.integers(1, model.cfg.vocab_size, 9).astype(np.int32),
+               max_new=3)
+    _drain(eng)
+    names = {n for n, _ in made}
+    assert {f"step.{p}" for p in PHASES} <= names
+    assert [kw["step"] for n, kw in made if n == "step.wait"] == \
+        list(range(eng.obs.steps))
+    gc.collect()
+    assert ("gc", {}) in made
+    eng.attach_obs(None)
+    assert gc.callbacks == before
+
+
+def test_wall_ttft_counts_the_wait(qwen_model):
+    """On a wall clock the TTFT observation runs to when the ids reached
+    the host, so it covers the step's wait; ``first_token_at`` stays the
+    step's start."""
+    model, params = qwen_model
+    obs = Observability.create(trace=True)
+    eng = PagedLLMEngine(model, params, num_blocks=32, block_size=8,
+                         max_batch=4, max_len=32, obs=obs)
+    eng.submit(np.arange(1, 10, dtype=np.int32), max_new=2, now=0.0)
+    k, req = 0, eng.queue[0]
+    while req.first_token_at is None:
+        k += 1
+        eng.step(now=float(k))
+    assert req.first_token_at == float(k)
+    wait_ms = _step_events(obs)[-1]["args"]["phase_ms"]["wait"]
+    ttft = obs.metrics.histogram("request_ttft_seconds")
+    assert ttft.count == 1
+    assert ttft.sum - req.first_token_at >= wait_ms / 1e3 > 0
+    first = [e for e in obs.trace.to_chrome()["traceEvents"]
+             if e["name"] == "first_token"]
+    assert first[0]["ts"] == pytest.approx(ttft.sum * 1e6, abs=1e-3)
 
 
 # ------------------------------------------- app tier + CLI rendering
