@@ -278,13 +278,19 @@ def write_chunk_tokens(pools: Any, caches: Any, src_rows: Any,
                 out[name] = walk(pleaf, cnode[name], axis)
                 continue
             pre = (slice(None),) * axis
+            # the period axis is indexed too, so every update is a point
+            # write with no window over it: a window over the period axis
+            # makes the TPU compiler relayout the whole ``pos`` leaf
+            # before the scatter and back after it
+            per = (jnp.arange(pleaf.shape[0], dtype=jnp.int32)[:, None],) \
+                if axis else ()
             if name in _STATE_LEAVES:
                 assert rows is not None, "state pools need state_rows"
-                out[name] = pleaf.at[pre + (rows,)].set(
+                out[name] = pleaf.at[per + (rows,)].set(
                     cnode[name].astype(pleaf.dtype))
             else:
                 vals = cnode[name][pre + (sr, sl)].astype(pleaf.dtype)
-                out[name] = pleaf.at[pre + (db, dl)].set(vals)
+                out[name] = pleaf.at[per + (db, dl)].set(vals)
         return out
 
     return {"periods": walk(pools.get("periods", {}),

@@ -707,12 +707,18 @@ class PagedLLMEngine(_EngineObsMixin):
                 out = jnp.argmax(logits, axis=-1).astype(jnp.int32) \
                     if all_logits else logits
                 return out, pools
-            return jax.jit(go, static_argnums=7)
+            # a step that returns the pool consumes the pool it was
+            # given: donated, the scatter updates it in place instead of
+            # copying every K/V leaf into a fresh output buffer first.
+            # Callers rebind ``self.pools`` to the result and keep no
+            # reference to the pool they passed in.
+            return jax.jit(go, static_argnums=7, donate_argnums=(2,))
         self._prefill_paged = _prefill_entry(False)
         self._prefill_verify = _prefill_entry(True)
         self._decode = jax.jit(
             lambda p, pools, bt, t, pos, act: model.decode_step_paged(
-                p, pools, bt, t, pos, act, decode_kernel=decode_kernel))
+                p, pools, bt, t, pos, act, decode_kernel=decode_kernel),
+            donate_argnums=(1,))
 
     def _resolve_buckets(self, spec) -> Optional[List[int]]:
         """"auto" / "off" / explicit ascending lengths -> bucket list

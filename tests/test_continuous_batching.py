@@ -272,6 +272,49 @@ def test_decode_fusion_token_identity_and_no_growth(qwen_model):
     assert eng.stats()["decode_compiles"] == 0
 
 
+# ------------------------------------------------------- pool donation
+
+
+@pytest.mark.parametrize("kw", [
+    dict(),                                  # fused: _prefill_verify
+    dict(decode_fusion=False),               # _prefill_paged + _decode
+    dict(scheduler="serial"),
+], ids=["fused", "unfused", "serial"])
+def test_step_consumes_the_pool_it_is_given(qwen_model, kw):
+    """Every jitted step that returns the pool donates the pool it was
+    given: the leaves held before a step are deleted after it and the
+    engine's new pool is live.  A copy-on-write admission and a
+    preemption afterwards still give the no-cache tokens, so no path
+    reads a pool a step has consumed."""
+    import jax
+
+    model, params = qwen_model
+    rng = np.random.default_rng(7)
+    pa = rng.integers(1, model.cfg.vocab_size, 14).astype(np.int32)
+    pb = pa.copy()                # 2 full blocks + 2 lanes of block 2 shared
+    pb[10] = (int(pb[10]) % (model.cfg.vocab_size - 2)) + 1
+    pc = rng.integers(1, model.cfg.vocab_size, 9).astype(np.int32)
+    _, ref = _drive(model, params, [pa, pb, pc], **kw)
+
+    eng = PagedLLMEngine(model, params, num_blocks=33, block_size=4,
+                         max_batch=4, max_len=32, prefix_cache=True, **kw)
+    outs = {}
+    eng.submit(pa, max_new=6)
+    while not eng.idle:          # one request: no eager update before a step
+        held = jax.tree.leaves(eng.pools)
+        outs.update((r.rid, list(r.out_tokens)) for r in eng.step())
+        assert all(leaf.is_deleted() for leaf in held)
+        assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(eng.pools))
+    eng.submit(pb, max_new=6)
+    eng.submit(pc, max_new=6)
+    eng.step()
+    assert eng.cow_copies == 1
+    eng._preempt_youngest()
+    assert eng.preemptions == 1
+    outs.update(_drain(eng))
+    assert outs == ref
+
+
 # ------------------------------------------------------ knob validation
 
 
