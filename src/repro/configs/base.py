@@ -8,7 +8,8 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 ARCH_IDS = (
     "whisper-tiny",
@@ -36,6 +37,20 @@ _MODULE_FOR = {
     "grok-1-314b": "grok1_314b",
     "mnist-cnn": "mnist_cnn",
 }
+
+
+@dataclasses.dataclass(frozen=True)
+class LongRoPE:
+    """LongRoPE scaling of the rotary frequencies (Phi-3 / Phi-4
+    ``rope_scaling`` of type ``longrope``), short regime: each inverse
+    frequency is divided by its ``short_factor`` (one per rotated
+    frequency, ``rotary_dim / 2``), and cos and sin are scaled by
+    ``ModelConfig.rope_attention_factor``.  The engines serve contexts up
+    to ``original_max_position`` only, so the long regime's factors are
+    not carried."""
+
+    short_factor: Tuple[float, ...]
+    original_max_position: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,6 +82,9 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     rope_theta_local: float = 0.0       # "attn_local" layers (0 = same);
                                         # gemma3: 10k local / 1M global
+    partial_rotary_factor: float = 1.0  # share of each head's dims rotated
+    rope_scaling: Optional[LongRoPE] = None
+    norm_eps: float = 1e-6              # epsilon of every norm
     qkv_bias: bool = False
     qk_norm: bool = False
     tie_embeddings: bool = False
@@ -112,6 +130,22 @@ class ModelConfig:
         return self.d_model // max(self.num_heads, 1)
 
     @property
+    def rotary_dim(self) -> int:
+        """Leading dims of each head that RoPE rotates; the rest pass
+        through."""
+        return int(self.resolved_head_dim * self.partial_rotary_factor)
+
+    @property
+    def rope_attention_factor(self) -> float:
+        """LongRoPE's scale of cos and sin: sqrt(1 + ln(s) / ln(L0)) for
+        s = max_position / L0 > 1, L0 the original context; else 1."""
+        rs = self.rope_scaling
+        if rs is None or self.max_position <= rs.original_max_position:
+            return 1.0
+        s = self.max_position / rs.original_max_position
+        return math.sqrt(1.0 + math.log(s) / math.log(rs.original_max_position))
+
+    @property
     def kinds_for_layers(self) -> Tuple[str, ...]:
         reps = -(-self.num_layers // len(self.layer_kinds))
         return tuple((self.layer_kinds * reps)[: self.num_layers])
@@ -147,6 +181,11 @@ class ModelConfig:
         small_kinds = tuple(uniq[:2]) if uniq else ("attn",)
         d = min(self.d_model, 256)
         heads = 4 if self.num_heads else 0
+        head_dim = 64 if self.num_heads else 0
+        rs = self.rope_scaling
+        if rs is not None:      # one factor per frequency the smaller head rotates
+            n = int(head_dim * self.partial_rotary_factor) // 2
+            rs = dataclasses.replace(rs, short_factor=rs.short_factor[:n])
         kv = min(self.num_kv_heads, heads) or heads
         if self.num_kv_heads == 1:
             kv = 1
@@ -159,7 +198,8 @@ class ModelConfig:
             d_model=d,
             num_heads=heads,
             num_kv_heads=kv,
-            head_dim=64 if self.num_heads else 0,
+            head_dim=head_dim,
+            rope_scaling=rs,
             d_ff=min(self.d_ff, 512),
             vocab_size=min(self.vocab_size, 512),
             num_experts=min(self.num_experts, 4),

@@ -104,7 +104,7 @@ class Model:
                                     bidir_prefix=prefix, remat=remat)
             if prefix:
                 x = x[:, prefix:]
-        x = norm_apply(params["final_norm"], x, cfg.norm_kind)
+        x = norm_apply(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
         logits = unembed_apply(params["embed"], cfg, x)
         return logits, aux
 
@@ -129,7 +129,7 @@ class Model:
             x, _, caches = tf.stack_prefill(params["stack"], cfg, x, positions,
                                             cache_max=cache_max,
                                             bidir_prefix=prefix)
-        x = norm_apply(params["final_norm"], x, cfg.norm_kind)
+        x = norm_apply(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
         logits = unembed_apply(params["embed"], cfg, x[:, -1:, :])
         return logits, caches
 
@@ -144,7 +144,7 @@ class Model:
                                           caches, posc)
         else:
             x, caches = tf.stack_decode(params["stack"], cfg, x, caches, posc)
-        x = norm_apply(params["final_norm"], x, cfg.norm_kind)
+        x = norm_apply(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
         logits = unembed_apply(params["embed"], cfg, x)
         return logits, caches
 
@@ -249,7 +249,7 @@ class Model:
                                            pools, block_table, start_pos,
                                            cache_max, seq_len=seq_len,
                                            state_rows=state_rows)
-        x = norm_apply(params["final_norm"], x, cfg.norm_kind)
+        x = norm_apply(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
         if all_logits:
             return unembed_apply(params["embed"], cfg, x), caches
         if seq_len is None:
@@ -274,7 +274,7 @@ class Model:
         x, pools = tf.stack_decode_paged(params["stack"], cfg, x, pools,
                                          block_table, posc, active,
                                          decode_kernel=decode_kernel)
-        x = norm_apply(params["final_norm"], x, cfg.norm_kind)
+        x = norm_apply(params["final_norm"], x, cfg.norm_kind, cfg.norm_eps)
         logits = unembed_apply(params["embed"], cfg, x)
         return logits, pools
 

@@ -85,6 +85,17 @@ def _theta_for(cfg: ModelConfig, kind: str) -> float:
     return cfg.rope_theta
 
 
+def rope_args(cfg: ModelConfig, kind: str) -> dict:
+    """``apply_rope``'s arguments for a layer of ``kind``: its base, the
+    rotated dims and, under LongRoPE, the short-regime factors and the
+    attention factor (the engines refuse a context past
+    ``original_max_position``, ``serving/server.py``)."""
+    rs = cfg.rope_scaling
+    return dict(theta=_theta_for(cfg, kind), rotary_dim=cfg.rotary_dim,
+                factors=rs.short_factor if rs else None,
+                scale=cfg.rope_attention_factor)
+
+
 def _project_qkv(p, cfg: ModelConfig, x, positions, rope: bool,
                  kind: str = "attn"):
     """x (B,S,D) -> q (B,S,H,hd), k/v (B,S,KV,hd); RoPE at ``positions``."""
@@ -96,12 +107,12 @@ def _project_qkv(p, cfg: ModelConfig, x, positions, rope: bool,
         k = k + p["bk"]
         v = v + p["bv"]
     if cfg.qk_norm and "q_norm" in p:
-        q = _rms(q, p["q_norm"])
-        k = _rms(k, p["k_norm"])
+        q = _rms(q, p["q_norm"], cfg.norm_eps)
+        k = _rms(k, p["k_norm"], cfg.norm_eps)
     if rope:
-        theta = _theta_for(cfg, kind)
-        q = apply_rope(q, positions, theta)
-        k = apply_rope(k, positions, theta)
+        kw = rope_args(cfg, kind)
+        q = apply_rope(q, positions, **kw)
+        k = apply_rope(k, positions, **kw)
     q = shard(q, "batch", "seq", "heads", "head_dim")
     k = shard(k, "batch", "seq", "kv_heads", "head_dim")
     v = shard(v, "batch", "seq", "kv_heads", "head_dim")

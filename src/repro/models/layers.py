@@ -42,23 +42,34 @@ def norm_apply(p, x, kind: str, eps: float = 1e-6):
 # ---------------------------------------------------------------- RoPE
 
 
-def rope_angles(positions, head_dim: int, theta: float):
-    """positions: (...,) int -> (…, head_dim/2) angles."""
+def rope_angles(positions, head_dim: int, theta: float, factors=None):
+    """positions: (...,) int -> (…, head_dim/2) angles.  ``factors``
+    (head_dim/2,) divide the inverse frequencies (LongRoPE)."""
     half = head_dim // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if factors is not None:
+        freqs = freqs / jnp.asarray(factors, jnp.float32)
     return positions.astype(jnp.float32)[..., None] * freqs
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (B, S, H, hd); positions: (B, S) or (S,)."""
-    hd = x.shape[-1]
-    ang = rope_angles(positions, hd, theta)          # (B, S, hd/2)
-    cos = jnp.cos(ang)[..., None, :]                 # (B, S, 1, hd/2)
+def apply_rope(x, positions, theta: float, rotary_dim: int,
+               factors=None, scale: float = 1.0):
+    """x: (B, S, H, hd); positions: (B, S) or (S,).  Rotates the first
+    ``rotary_dim`` dims of each head as two halves; the rest pass
+    through.  ``factors`` divide the inverse frequencies and ``scale``
+    multiplies cos and sin (LongRoPE)."""
+    rd, hd = rotary_dim, x.shape[-1]
+    ang = rope_angles(positions, rd, theta, factors)  # (B, S, rd/2)
+    cos = jnp.cos(ang)[..., None, :]                 # (B, S, 1, rd/2)
     sin = jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    y1 = x1 * cos - x2 * sin
-    y2 = x2 * cos + x1 * sin
-    return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf[..., :rd] if rd < hd else xf, 2, axis=-1)
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rd < hd:
+        parts.append(xf[..., rd:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 # ---------------------------------------------------------------- MLP

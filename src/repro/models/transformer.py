@@ -98,7 +98,7 @@ def _ffn(p, cfg: ModelConfig, x, moe: bool):
 def block_apply(p, cfg: ModelConfig, x, positions, *, kind: str, moe: bool,
                 bidir_prefix: int = 0):
     """Train/eval forward (no cache).  -> (x, aux_loss)."""
-    h = norm_apply(p["norm1"], x, cfg.norm_kind)
+    h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
     if kind in ("attn", "attn_local"):
         y = attn.attn_apply(p["mix"], cfg, h, positions, kind=kind,
                             bidir_prefix=bidir_prefix)
@@ -108,12 +108,12 @@ def block_apply(p, cfg: ModelConfig, x, positions, *, kind: str, moe: bool,
         y, _ = rwkv_mod.rwkv_time_mix(
             p["mix"], cfg, h, rwkv_mod.init_state(cfg, x.shape[0], x.dtype))
         x = x + y
-        h2 = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h2 = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         y2, _ = rwkv_mod.channel_mix(
             p["ffn"], cfg, h2, jnp.zeros(h2.shape[:1] + h2.shape[2:], h2.dtype))
         return x + y2, jnp.float32(0.0)
     x = x + y
-    h = norm_apply(p["norm2"], x, cfg.norm_kind)
+    h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
     y, aux = _ffn(p, cfg, h, moe)
     return x + y, aux
 
@@ -121,7 +121,7 @@ def block_apply(p, cfg: ModelConfig, x, positions, *, kind: str, moe: bool,
 def block_prefill(p, cfg: ModelConfig, x, positions, *, kind: str, moe: bool,
                   cache_max: int, bidir_prefix: int = 0):
     """Forward + build the decode cache.  -> (x, aux, cache)."""
-    h = norm_apply(p["norm1"], x, cfg.norm_kind)
+    h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
     if kind in ("attn", "attn_local"):
         y, cache = attn.attn_prefill(p["mix"], cfg, h, positions, kind=kind,
                                      cache_max=cache_max,
@@ -132,19 +132,19 @@ def block_prefill(p, cfg: ModelConfig, x, positions, *, kind: str, moe: bool,
         st = rwkv_mod.init_state(cfg, x.shape[0], x.dtype)
         y, part = rwkv_mod.rwkv_time_mix(p["mix"], cfg, h, st)
         x = x + y
-        h2 = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h2 = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         y2, x_cm = rwkv_mod.channel_mix(p["ffn"], cfg, h2, st["x_cm"])
         cache = {"s": part["s"], "x_tm": part["x_tm"], "x_cm": x_cm}
         return x + y2, jnp.float32(0.0), cache
     x = x + y
-    h = norm_apply(p["norm2"], x, cfg.norm_kind)
+    h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
     y, aux = _ffn(p, cfg, h, moe)
     return x + y, aux, cache
 
 
 def block_decode(p, cfg: ModelConfig, x, cache, pos, *, kind: str, moe: bool):
     """One-token step.  x (B,1,D), pos (B,).  -> (x, new_cache)."""
-    h = norm_apply(p["norm1"], x, cfg.norm_kind)
+    h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
     if kind in ("attn", "attn_local"):
         y, cache = attn.attn_decode(p["mix"], cfg, h, cache, pos, kind=kind)
     elif kind == "mamba":
@@ -152,11 +152,11 @@ def block_decode(p, cfg: ModelConfig, x, cache, pos, *, kind: str, moe: bool):
     else:  # rwkv6
         y, part = rwkv_mod.rwkv_time_mix(p["mix"], cfg, h, cache)
         x = x + y
-        h2 = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h2 = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         y2, x_cm = rwkv_mod.channel_mix(p["ffn"], cfg, h2, cache["x_cm"])
         return x + y2, {"s": part["s"], "x_tm": part["x_tm"], "x_cm": x_cm}
     x = x + y
-    h = norm_apply(p["norm2"], x, cfg.norm_kind)
+    h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
     y, _ = _ffn(p, cfg, h, moe)
     return x + y, cache
 
@@ -317,17 +317,17 @@ def _recurrent_fwd(p, cfg: ModelConfig, x, st, *, kind: str, moe: bool,
                    seq_len=None):
     """Shared mamba/rwkv6 block body over an explicit state dict.
     -> (x, new_state)."""
-    h = norm_apply(p["norm1"], x, cfg.norm_kind)
+    h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
     if kind == "mamba":
         y, new_st = ssm_mod.ssm_forward(p["mix"], cfg, h, st, seq_len=seq_len)
         x = x + y
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         y, _ = _ffn(p, cfg, h, moe)
         return x + y, new_st
     # rwkv6: two-norm structure — channel-mix replaces the FFN
     y, part = rwkv_mod.rwkv_time_mix(p["mix"], cfg, h, st, seq_len=seq_len)
     x = x + y
-    h2 = norm_apply(p["norm2"], x, cfg.norm_kind)
+    h2 = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
     y2, x_cm = rwkv_mod.channel_mix(p["ffn"], cfg, h2, st["x_cm"],
                                     seq_len=seq_len)
     return x + y2, {"s": part["s"], "x_tm": part["x_tm"], "x_cm": x_cm}
@@ -341,12 +341,12 @@ def block_decode_paged(p, cfg: ModelConfig, x, pool, block_table, pos, active,
     one extra trash row for padded prefill dispatches).  ``decode_kernel``:
     Pallas kernel vs jnp gather (attn_decode_paged)."""
     if kind in ("attn", "attn_local"):
-        h = norm_apply(p["norm1"], x, cfg.norm_kind)
+        h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
         y, pool = attn.attn_decode_paged(p["mix"], cfg, h, pool, block_table,
                                          pos, active, kind=kind,
                                          decode_kernel=decode_kernel)
         x = x + y
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         y, _ = _ffn(p, cfg, h, moe)
         return x + y, pool
     if kind not in ("mamba", "rwkv6"):
@@ -412,13 +412,13 @@ def block_prefill_paged(p, cfg: ModelConfig, x, positions, pool, block_table,
     dispatch row to its slot, and the emitted "cache" is the chunk-exit
     state for the engine to scatter back."""
     if kind in ("attn", "attn_local"):
-        h = norm_apply(p["norm1"], x, cfg.norm_kind)
+        h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
         y, cache = attn.attn_prefill_paged(p["mix"], cfg, h, positions, pool,
                                            block_table, start_pos, kind=kind,
                                            cache_max=cache_max,
                                            seq_len=seq_len)
         x = x + y
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         y, _ = _ffn(p, cfg, h, moe)
         return x + y, cache
     if kind not in ("mamba", "rwkv6"):
@@ -587,11 +587,11 @@ def encoder_apply(params, cfg: ModelConfig, frames):
     pos = jnp.arange(frames.shape[1], dtype=jnp.int32)
     for i in range(cfg.encoder_layers):
         p = params["encoder"][f"layer{i}"]
-        h = norm_apply(p["norm1"], x, cfg.norm_kind)
+        h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + attn.attn_apply(p["attn"], cfg, h, pos, causal=False)
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + mlp_apply(p["ffn"], h, cfg.mlp_kind)
-    return norm_apply(params["enc_norm"], x, cfg.norm_kind)
+    return norm_apply(params["enc_norm"], x, cfg.norm_kind, cfg.norm_eps)
 
 
 def decoder_apply(params, cfg: ModelConfig, x, positions, enc_out):
@@ -602,12 +602,12 @@ def decoder_apply(params, cfg: ModelConfig, x, positions, enc_out):
     }
     for i in range(cfg.num_layers):
         p = params[f"layer{i}"]
-        h = norm_apply(p["norm1"], x, cfg.norm_kind)
+        h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + attn.attn_apply(p["attn"], cfg, h, positions)
-        h = norm_apply(p["norm_x"], x, cfg.norm_kind)
+        h = norm_apply(p["norm_x"], x, cfg.norm_kind, cfg.norm_eps)
         k, v = cross_kvs[i]
         x = x + attn.cross_apply(p["cross"], cfg, h, k, v)
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + mlp_apply(p["ffn"], h, cfg.mlp_kind)
     return x
 
@@ -617,14 +617,14 @@ def decoder_prefill(params, cfg: ModelConfig, x, positions, enc_out,
     caches = {}
     for i in range(cfg.num_layers):
         p = params[f"layer{i}"]
-        h = norm_apply(p["norm1"], x, cfg.norm_kind)
+        h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
         y, c = attn.attn_prefill(p["attn"], cfg, h, positions, kind="attn",
                                  cache_max=cache_max)
         x = x + y
-        h = norm_apply(p["norm_x"], x, cfg.norm_kind)
+        h = norm_apply(p["norm_x"], x, cfg.norm_kind, cfg.norm_eps)
         k, v = attn.cross_kv(p["cross"], enc_out)
         x = x + attn.cross_apply(p["cross"], cfg, h, k, v)
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + mlp_apply(p["ffn"], h, cfg.mlp_kind)
         caches[f"layer{i}"] = {"self": c, "xk": k, "xv": v}
     return x, caches
@@ -635,12 +635,12 @@ def decoder_decode(params, cfg: ModelConfig, x, caches, pos):
     for i in range(cfg.num_layers):
         p = params[f"layer{i}"]
         c = caches[f"layer{i}"]
-        h = norm_apply(p["norm1"], x, cfg.norm_kind)
+        h = norm_apply(p["norm1"], x, cfg.norm_kind, cfg.norm_eps)
         y, sc = attn.attn_decode(p["attn"], cfg, h, c["self"], pos, kind="attn")
         x = x + y
-        h = norm_apply(p["norm_x"], x, cfg.norm_kind)
+        h = norm_apply(p["norm_x"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + attn.cross_apply(p["cross"], cfg, h, c["xk"], c["xv"])
-        h = norm_apply(p["norm2"], x, cfg.norm_kind)
+        h = norm_apply(p["norm2"], x, cfg.norm_kind, cfg.norm_eps)
         x = x + mlp_apply(p["ffn"], h, cfg.mlp_kind)
         new[f"layer{i}"] = {"self": sc, "xk": c["xk"], "xv": c["xv"]}
     return x, new

@@ -14,6 +14,8 @@ gauge schema lives in ``serving/stats_schema.py``):
   engine_requests_total / engine_admissions_total /
   engine_preemptions_total / engine_finished_total          counters
   engine_prefill_tokens_total / engine_generated_tokens_total
+  engine_recomputed_tokens_total (prefill tokens of preempted
+    requests recomputed on resume; also in engine_prefill_tokens_total)
   engine_steps_total
   engine_queue_depth / engine_active / engine_free_blocks /
   engine_pool_occupancy                                     gauges
@@ -104,7 +106,8 @@ GC_SPANS = _GcSpans()
 class EngineObs:
     __slots__ = ("bundle", "trace", "wall",
                  "c_requests", "c_admissions", "c_preemptions",
-                 "c_finished", "c_prefill_tokens", "c_generated",
+                 "c_finished", "c_prefill_tokens", "c_recomputed",
+                 "c_generated",
                  "c_steps", "g_queue", "g_active", "g_free_blocks",
                  "g_occupancy", "h_step", "h_ttft", "h_e2e", "h_gap",
                  "c_spec_proposed", "c_spec_accepted", "c_spec_rollbacks",
@@ -136,6 +139,10 @@ class EngineObs:
             "engine_finished_total", "requests finished", lab)
         self.c_prefill_tokens = m.counter(
             "engine_prefill_tokens_total", "prompt tokens computed", lab)
+        self.c_recomputed = m.counter(
+            "engine_recomputed_tokens_total",
+            "prefill tokens recomputed by preempted requests on resume",
+            lab)
         self.c_generated = m.counter(
             "engine_generated_tokens_total", "output tokens emitted", lab)
         self.c_steps = m.counter(
@@ -192,16 +199,23 @@ class EngineObs:
             self.trace.request(rid, "queued", now)
 
     def admitted(self, rid: int, now: float, resume: bool,
-                 cached_blocks: int, cow: bool) -> None:
+                 cached_blocks: int, cow: bool, recomputed: int = 0) -> None:
+        """``recomputed``: of the positions whose KV a resumed request
+        held before its preemption, those past its cached match, which
+        its prefill will compute again."""
         self.c_admissions.inc()
         if self.trace:
+            extra = {"recomputed_tokens": recomputed} if resume else {}
             self.trace.request(rid, "evicted_resume" if resume
                                else "admitted", now,
-                               cached_blocks=cached_blocks, cow=cow)
+                               cached_blocks=cached_blocks, cow=cow, **extra)
 
     def prefill_chunk(self, rid: int, now: float, start: int,
-                      take: int) -> None:
+                      take: int, recomputed: int = 0) -> None:
+        """``recomputed``: lanes of the chunk whose KV the request held
+        before a preemption."""
         self.c_prefill_tokens.inc(take)
+        self.c_recomputed.inc(recomputed)
         if self.trace:
             self.trace.request(rid, "prefill_chunk", now, start=start,
                                take=take)

@@ -282,6 +282,17 @@ class _EngineObsMixin:
         req.last_token_at = now
 
 
+def check_rope_context(cfg, context: int) -> None:
+    """Refuse a context past a LongRoPE config's original context: the
+    engines apply its short-regime factors only."""
+    rs = cfg.rope_scaling
+    if rs is not None and context > rs.original_max_position:
+        raise ValueError(
+            f"{cfg.name}: context {context} exceeds the LongRoPE original "
+            f"context of {rs.original_max_position} tokens; only the "
+            "short-regime factors are served")
+
+
 class LLMEngine(_EngineObsMixin):
     """Continuous-batching decode over the unified Model API."""
 
@@ -290,6 +301,7 @@ class LLMEngine(_EngineObsMixin):
     def __init__(self, model, params, num_slots: int = 4,
                  cache_max: int = 512, eos_id: Optional[int] = None,
                  obs=None):
+        check_rope_context(model.cfg, cache_max)
         self.model = model
         self.params = params
         self.slots = SlotManager(num_slots)
@@ -571,6 +583,7 @@ class PagedLLMEngine(_EngineObsMixin):
         if admission_window < 1:
             raise ValueError(f"admission_window must be >= 1, "
                              f"got {admission_window}")
+        check_rope_context(model.cfg, max_len)
         self.model = model
         self.params = params
         self.block_size = block_size
@@ -623,10 +636,15 @@ class PagedLLMEngine(_EngineObsMixin):
         self.finished_count = 0
         self.peak_active = 0
         self.prefill_tokens = 0
+        # prefill tokens recomputed by preempted requests on resume
+        self.recomputed_tokens = 0
         self.generated_tokens = 0
         self.cow_copies = 0
         self._decode_batch_last = 0
-        self._preempted_rids: set = set()
+        # preempted request -> how many leading positions' KV it held
+        # (the most over its preemptions): resumed chunks below that mark
+        # are counted in ``recomputed_tokens``
+        self._held_kv: Dict[int, int] = {}
         self.admission_window = admission_window
         self.admission_skips = 0
         # speculative decoding: drafter proposes, target verifies in the
@@ -829,6 +847,7 @@ class PagedLLMEngine(_EngineObsMixin):
             "finished": self.finished_count,
             "peak_active": self.peak_active,
             "prefill_tokens": self.prefill_tokens,
+            "recomputed_tokens": self.recomputed_tokens,
             "prefix_cache": int(pc is not None),
             "hit_rate": pc.hit_rate if pc else 0.0,
             "cached_blocks": pc.cached_blocks if pc else 0,
@@ -1142,10 +1161,10 @@ class PagedLLMEngine(_EngineObsMixin):
         self.peak_active = max(self.peak_active,
                                len(self.active) + len(self.prefilling))
         if self.obs:
-            resume = req.rid in self._preempted_rids
-            self._preempted_rids.discard(req.rid)
-            self.obs.admitted(req.rid, now, resume=resume,
-                              cached_blocks=k, cow=bool(j))
+            held = self._held_kv.get(req.rid)
+            self.obs.admitted(req.rid, now, resume=held is not None,
+                              cached_blocks=k, cow=bool(j),
+                              recomputed=max(0, (held or 0) - start))
 
     def _select_chunks(self) -> tuple:
         """Pick this step's prefill chunks: oldest request first, each
@@ -1206,8 +1225,11 @@ class PagedLLMEngine(_EngineObsMixin):
 
     def _ragged_inputs(self, rows: List[tuple], state_rows,
                        all_logits: bool) -> tuple:
-        """Host side of ``_ragged_dispatch``: the padded tokens, and the
-        table, cursors, lengths and state rows moved to the device.
+        """Host side of ``_ragged_dispatch``: the padded tokens, table,
+        cursors, lengths and state rows, as numpy arrays.  The jitted
+        step's own dispatch moves them to the device, which takes less
+        host time than a Python-level device put apiece, on a path that
+        every engine step waits for.
         -> (tokens, (table, starts, lens, state rows, c_pad))."""
         r_pad = self._bucket_rows(len(rows))
         # decode-only fused steps are all length-1 windows: dispatch at
@@ -1231,8 +1253,7 @@ class PagedLLMEngine(_EngineObsMixin):
         if state_rows is not None:
             srows[:len(state_rows)] = state_rows
         self._prefill_sigs.add((r_pad, c_pad, nb_pad, all_logits))
-        return toks, (jnp.asarray(bt), jnp.asarray(starts),
-                      jnp.asarray(lens), jnp.asarray(srows), c_pad)
+        return toks, (bt, starts, lens, srows, c_pad)
 
     def _chunk_rows(self, sel: List[tuple]) -> List[tuple]:
         return [(self.prefilling[r].seq[self.prefilling[r].done:
@@ -1247,15 +1268,19 @@ class PagedLLMEngine(_EngineObsMixin):
         token when the prefill completes."""
         for i, (r, take) in enumerate(sel):
             st = self.prefilling[r]
+            again = max(0, min(st.done + take,
+                               self._held_kv.get(st.req.rid, 0)) - st.done)
             if self.obs:
-                self.obs.prefill_chunk(st.req.rid, now, st.done, take)
+                self.obs.prefill_chunk(st.req.rid, now, st.done, take, again)
             last = st.completes(take)
             st.done += take
             self.prefill_tokens += take
+            self.recomputed_tokens += again
             # window stacks: blocks the chunk just slid out of the live
             # window die immediately (next query position = st.done)
             self._window_shrink(st.all_blocks, st.done)
             if last:
+                self._held_kv.pop(st.req.rid, None)
                 self._finish_prefill(r, tok_at(i, take), now)
 
     def _prefill_chunks(self, now: float) -> None:
@@ -1497,15 +1522,18 @@ class PagedLLMEngine(_EngineObsMixin):
         req = rows[row]
         where = "prefill" if row in self.prefilling else "decode"
         if row in self.prefilling:
-            self._free_blocks(self.prefilling.pop(row).all_blocks)
+            st = self.prefilling.pop(row)
+            held = st.done
+            self._free_blocks(st.all_blocks)
         else:
+            held = int(self.pos[row])     # the last token's KV is unwritten
             del self.active[row]
             self._free_blocks(self.row_blocks.pop(row))
+        self._held_kv[req.rid] = max(held, self._held_kv.get(req.rid, 0))
         self.block_table[row, :] = 0
         self.pos[row] = 0
         self.queue.insert(0, req)             # resumes as soon as blocks free
         self.preemptions += 1
-        self._preempted_rids.add(req.rid)
         if self.obs:
             self.obs.preempted(req.rid, now, where)
 

@@ -59,6 +59,10 @@ SCHEMA = {
                           BOTH + CLUSTER),
     "peak_active": GaugeSpec("high-water concurrent requests", PAGED),
     "prefill_tokens": GaugeSpec("prompt tokens actually computed", PAGED),
+    "recomputed_tokens": GaugeSpec("prefill tokens whose KV a preempted "
+                                   "request held before and computed again "
+                                   "on resume (counted in prefill_tokens "
+                                   "too)", PAGED),
     "prefix_cache": GaugeSpec("1 when the radix prefix cache is on",
                               PAGED),
     "hit_rate": GaugeSpec("prompt tokens served from cache / all prompt "

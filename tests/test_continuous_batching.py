@@ -249,6 +249,32 @@ def test_continuous_retrace_gauge_matches_jit_cache(qwen_model):
     assert so["decode_compiles"] == 1
 
 
+def test_ragged_inputs_are_padded_host_arrays(qwen_model):
+    """The fused step's inputs leave ``_ragged_inputs`` as numpy arrays,
+    for the jitted step's own dispatch to move, padded to the row, length
+    and block buckets: padding rows carry one garbage lane, the null
+    table and the trash state slot."""
+    model, params = qwen_model
+    eng = PagedLLMEngine(model, params, num_blocks=64, block_size=8,
+                         max_batch=8, max_len=96)
+    rows = [(np.array([5, 6, 7], np.int32), 0, [1]),
+            (np.array([9], np.int32), 12, [2, 3])]
+    toks, (bt, starts, lens, srows, c_pad) = eng._ragged_inputs(
+        rows, None, True)
+    for a in (toks, bt, starts, lens, srows):
+        assert isinstance(a, np.ndarray) and a.dtype == np.int32
+    r_pad = eng._bucket_rows(2)
+    assert toks.shape == (r_pad, c_pad) and c_pad == eng._bucket_len(3)
+    assert bt.shape == (r_pad, eng._bucket_blocks(2))
+    np.testing.assert_array_equal(toks[0, :3], [5, 6, 7])
+    np.testing.assert_array_equal(toks[1, :1], [9])
+    np.testing.assert_array_equal(bt[:2, :2], [[1, 0], [2, 3]])
+    np.testing.assert_array_equal(starts[:2], [0, 12])
+    np.testing.assert_array_equal(lens, [3, 1] + [1] * (r_pad - 2))
+    np.testing.assert_array_equal(srows, [eng.max_batch] * r_pad)
+    assert not bt[2:].any() and not starts[2:].any()
+
+
 def test_decode_fusion_token_identity_and_no_growth(qwen_model):
     """Decode fusion is a pure dispatch change: token-identical to the
     unfused continuous scheduler, and re-running the same workload on

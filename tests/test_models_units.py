@@ -41,7 +41,7 @@ def test_layernorm_zero_mean():
 def test_rope_preserves_norm():
     x = jax.random.normal(KEY, (1, 6, 2, 64))
     pos = jnp.arange(6)
-    y = layers.apply_rope(x, pos, 10_000.0)
+    y = layers.apply_rope(x, pos, 10_000.0, rotary_dim=64)
     np.testing.assert_allclose(
         np.linalg.norm(np.asarray(x), axis=-1),
         np.linalg.norm(np.asarray(y), axis=-1), rtol=1e-5)
@@ -52,8 +52,8 @@ def test_rope_relative_property():
     q = jax.random.normal(KEY, (1, 1, 1, 32))
     k = jax.random.normal(jax.random.PRNGKey(1), (1, 1, 1, 32))
     def dot_at(m, n):
-        qq = layers.apply_rope(q, jnp.array([m]), 10_000.0)
-        kk = layers.apply_rope(k, jnp.array([n]), 10_000.0)
+        qq = layers.apply_rope(q, jnp.array([m]), 10_000.0, rotary_dim=32)
+        kk = layers.apply_rope(k, jnp.array([n]), 10_000.0, rotary_dim=32)
         return float(jnp.sum(qq * kk))
     assert abs(dot_at(5, 3) - dot_at(9, 7)) < 1e-4
     assert abs(dot_at(5, 3) - dot_at(6, 3)) > 1e-6
